@@ -23,6 +23,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 SUITES = [
     "solver_runtime",
     "load_capacity",
@@ -52,6 +54,7 @@ def main() -> None:
                     help="comma-separated suite substrings")
     args = ap.parse_args()
     want = [s for s in args.only.split(",") if s]
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

@@ -12,16 +12,17 @@ by their trip count, so it undercounts layer-stacked programs by ~L; we use
 the call-graph parser in hlo_parse.py (trip counts from known_trip_count)
 and report the XLA numbers alongside for reference.
 
-Hardware constants (TPU v5e-class, per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware constants are the TPU v5e row of ``core.capacity.PEAKS`` (per
+chip: 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s per ICI link).
 """
 from __future__ import annotations
 
 from repro.analysis.hlo_parse import parse_hlo
+from repro.core.capacity import PEAKS, TPU_V5E
 
-PEAK_FLOPS = 197e12       # bf16 per chip
-HBM_BW = 819e9            # bytes/s per chip
-ICI_BW = 50e9             # bytes/s per link
+PEAK_FLOPS = PEAKS[TPU_V5E].flops     # bf16 per chip
+HBM_BW = PEAKS[TPU_V5E].hbm_bw        # bytes/s per chip
+ICI_BW = PEAKS[TPU_V5E].ici_bw        # bytes/s per link
 
 
 def model_flops(arch, shape) -> float:

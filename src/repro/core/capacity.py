@@ -26,10 +26,33 @@ THRESHOLDS = {HIERARCHICAL: 0.0, REUSABLE: 0.20, ELEMENTAL: 3.00}
 
 
 @dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    flops: float        # dense bf16 FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    ici_bw: float       # chip-to-chip bytes/s per link
+    host_bw: float      # host->HBM bytes/s
+    source: str
+
+
+TPU_V5E = "TPU v5 lite"
+# One table of device peaks, keyed by ``jax.Device.device_kind``. A kind
+# that is not here is an error, never a default (``HWSpec.for_device``).
+PEAKS = {
+    TPU_V5E: ChipPeaks(
+        flops=197e12, hbm_bw=819e9,
+        ici_bw=1600e9 / 8 / 4,       # 1,600 Gbit/s over the chip's 4 links
+        host_bw=25e9,                # ASSUMED (PCIe-class), not published
+        source="Google Cloud TPU v5e documentation: 197 TFLOP/s bf16, "
+               "819 GB/s HBM, 1,600 Gbit/s ICI; host->HBM is assumed"),
+}
+
+
+@dataclass(frozen=True)
 class HWSpec:
-    peak_flops: float = 197e12       # bf16/chip (TPU v5e-class)
-    hbm_bw: float = 819e9
-    stream_bw: float = 25e9          # host->HBM streaming path (PCIe-class)
+    peak_flops: float = PEAKS[TPU_V5E].flops
+    hbm_bw: float = PEAKS[TPU_V5E].hbm_bw
+    stream_bw: float = PEAKS[TPU_V5E].host_bw   # host->HBM streaming path
     disk_bw: float = 0.0             # storage->host stage (0 = not modeled)
 
     def op_time(self, op: Op) -> float:
@@ -57,6 +80,21 @@ class HWSpec:
         bw = 4 * src.nbytes / max(time.perf_counter() - t0, 1e-9)
         return HWSpec(peak_flops=flops, hbm_bw=bw, stream_bw=bw / 2,
                       disk_bw=0.5e9)
+
+    @staticmethod
+    def for_device(dev) -> "HWSpec":
+        """Planner constants for the device that will execute: the peaks
+        table row for an accelerator, a measurement of this host for the
+        CPU backend. An accelerator kind missing from ``PEAKS`` raises."""
+        if dev.platform == "cpu":
+            return HWSpec.cpu_calibrated()
+        row = PEAKS.get(dev.device_kind)
+        if row is None:
+            raise ValueError(f"no peaks for device kind {dev.device_kind!r} "
+                             f"(platform {dev.platform}); add a row to "
+                             "repro.core.capacity.PEAKS")
+        return HWSpec(peak_flops=row.flops, hbm_bw=row.hbm_bw,
+                      stream_bw=row.host_bw)
 
 
 def analytic_capacity_bytes(op: Op, hw: HWSpec,

@@ -210,6 +210,9 @@ class RunStats:
     requests: int = 1            # user requests this run served (batch size)
     cache_hits: int = 0          # weight-pool probes served device-resident
     cache_misses: int = 0        # probes that had to stream from host/disk
+    preloaded_bytes: int = 0     # whole weights device_put before the op loop
+    streamed_chunks: int = 0     # chunks the loader device_put during it
+    streamed_bytes: int = 0
     result: Any = None
 
     @property
@@ -240,6 +243,14 @@ def quantize_chunk(arr: np.ndarray):
     return q, np.float32(scale)
 
 
+def put_chunk(chunk, device):
+    """device_put one host chunk (array or (int8, scale)) onto ``device``
+    (None = JAX's default device)."""
+    if isinstance(chunk, tuple):
+        return jax.device_put(chunk[0], device), float(chunk[1])
+    return jax.device_put(chunk, device)
+
+
 class _Loader(threading.Thread):
     """Dedicated load queue: walks the plan's chunk tasks in op order,
     emulating the storage stage at `disk_bw` (0 = RAM speed), device_puts
@@ -250,26 +261,43 @@ class _Loader(threading.Thread):
     When bound to a WeightCache, every chunk is probed in the pool first —
     prefetched or previously-streamed chunks skip the storage stage and the
     device_put entirely — and freshly-loaded chunks are checked in pinned
-    so LRU pressure cannot drop bytes that are about to be consumed."""
+    so LRU pressure cannot drop bytes that are about to be consumed.
+
+    An exception on this thread (e.g. HBM exhausted in a device_put) is
+    kept in ``error`` and re-raised by ``check`` on the compute thread."""
 
     def __init__(self, plan: OverlapPlan, host_chunks: Dict[str, list],
                  disk_bw: float, cache: Optional[WeightCache] = None,
-                 cache_key: str = ""):
+                 cache_key: str = "", device=None):
         super().__init__(daemon=True)
         self.plan = plan
         self.host_chunks = host_chunks
         self.disk_bw = disk_bw
         self.cache = cache
         self.cache_key = cache_key
+        self.device = device
+        self.error: Optional[Exception] = None
+        # op at whose load tasks the plan completes each weight; a weight
+        # absent here, or due after its consumer, is a plan miss
+        self.due: Dict[str, int] = {}
+        planned: Dict[str, int] = {}
+        for l in sorted(plan.loads):
+            for t in plan.loads[l]:
+                n = len(host_chunks[t.weight])
+                planned[t.weight] = planned.get(t.weight, 0) + \
+                    max(0, min(t.chunk_hi, n) - t.chunk_lo)
+                if planned[t.weight] >= n:
+                    self.due.setdefault(t.weight, l)
         self.arrived: Dict[str, list] = {}
         self.assembled: Dict[str, jax.Array] = {}   # whole-weight pool hits
         self.uncached_bytes: Dict[str, int] = {}    # pool-rejected transients
         self.ready: Dict[str, threading.Event] = {
             w: threading.Event() for w in host_chunks}
         self.gate: Dict[int, threading.Event] = {}
-        self.bytes_in_flight = 0
         self.hits = 0                                # loader-thread-local
         self.misses = 0
+        self.streamed_chunks = 0
+        self.streamed_bytes = 0
         self.lock = threading.Lock()
 
     def allow_through(self, op_index: int):
@@ -287,23 +315,46 @@ class _Loader(threading.Thread):
             cached = self.cache.acquire((self.cache_key, w, ci))
             if cached is not None:
                 self.hits += 1
-                return cached, int(nbytes)
+                return cached
             self.misses += 1
         if self.disk_bw > 0:
             time.sleep(nbytes / self.disk_bw)
-        if isinstance(chunk, tuple):
-            arr = (jax.device_put(chunk[0]), float(chunk[1]))
-        else:
-            arr = jax.device_put(chunk)
+        arr = put_chunk(chunk, self.device)
+        self.streamed_chunks += 1
+        self.streamed_bytes += int(nbytes)
         if self.cache is not None:
             if not self.cache.put((self.cache_key, w, ci), arr, nbytes,
                                   pin=True):
                 with self.lock:
                     self.uncached_bytes[w] = \
                         self.uncached_bytes.get(w, 0) + int(nbytes)
-        return arr, int(nbytes)
+        return arr
 
     def run(self):
+        try:
+            self._load_all()
+        except Exception as e:  # noqa: BLE001 — re-raised by check
+            self.error = e
+        finally:
+            for ev in self.ready.values():      # wake every waiter
+                ev.set()
+
+    def check(self):
+        """Raise on the calling thread if the loader failed."""
+        if self.error is not None:
+            raise RuntimeError(f"weight loader for {self.cache_key!r} "
+                               "failed") from self.error
+
+    def wait_ready(self, w: str, op_index: int):
+        """Block until the chunks the plan loads for ``w`` by compute op
+        ``op_index`` have arrived. A weight the plan does not complete by
+        then is a plan miss: return at once, and the caller completes it
+        synchronously. Raises if the loader failed."""
+        if self.due.get(w, op_index + 1) <= op_index:
+            self.ready[w].wait()
+        self.check()
+
+    def _load_all(self):
         for l in sorted(self.plan.loads):
             # the load queue may run at most one op "ahead window" — tasks
             # for op l are issued once compute reaches op l (the plan already
@@ -325,10 +376,9 @@ class _Loader(threading.Thread):
                     self.misses += 1
                 hcs = self.host_chunks[w]
                 for ci in range(task.chunk_lo, min(task.chunk_hi, len(hcs))):
-                    arr, nbytes = self._load_chunk(w, ci, hcs[ci])
+                    arr = self._load_chunk(w, ci, hcs[ci])
                     with self.lock:
                         self.arrived.setdefault(w, []).append(arr)
-                        self.bytes_in_flight += nbytes
                 if len(self.arrived.get(w, ())) >= len(hcs):
                     self.ready[w].set()
 
@@ -357,7 +407,7 @@ class StreamingExecutor:
                  disk_bw: float = 0.0, gate_loads: bool = True,
                  quantize_stream: bool = False,
                  cache: Optional[WeightCache] = None,
-                 cache_key: Optional[str] = None):
+                 cache_key: Optional[str] = None, device=None):
         # gate_loads paces the loader by compute progress: a task assigned
         # to op l is issued when compute reaches op l (the plan's lookahead
         # IS the overlap); ungated, a fast loader front-runs the plan and
@@ -367,7 +417,10 @@ class StreamingExecutor:
         # cache binds the run to a shared budgeted device pool: weights are
         # checked out of / into the pool, survive the run unpinned for
         # future requests, and residency reports the pool's global usage.
+        # device is where every weight, chunk and input is placed (None =
+        # JAX's default device).
         self.model = model
+        self.device = device
         self.plan = plan
         self.disk_bw = disk_bw
         self.gate_loads = gate_loads
@@ -415,7 +468,8 @@ class StreamingExecutor:
                 nbytes = m.host_weights[w].nbytes
                 if self.disk_bw > 0:
                     time.sleep(nbytes / self.disk_bw)
-                arr = jax.device_put(m.host_weights[w])
+                arr = jax.device_put(m.host_weights[w], self.device)
+                stats.preloaded_bytes += int(nbytes)
                 if cache is not None and not cache.put((key, w, "w"), arr,
                                                        nbytes, pin=True):
                     transient[w] = int(nbytes)
@@ -425,12 +479,12 @@ class StreamingExecutor:
         stats.init_s = time.perf_counter() - t0
 
         loader = _Loader(plan, host_chunks, self.disk_bw, cache=cache,
-                         cache_key=key)
+                         cache_key=key, device=self.device)
         if self.gate_loads:
             loader.gate = {l: threading.Event() for l in plan.loads}
         loader.start()
 
-        regs = {"tokens": jax.device_put(tokens)}
+        regs = {"tokens": jax.device_put(tokens, self.device)}
         return ExecState(tokens=tokens, stats=stats, host_chunks=host_chunks,
                          dev=dev, transient=transient, loader=loader,
                          regs=regs)
@@ -469,7 +523,7 @@ class StreamingExecutor:
                         if full is None:
                             if not loader.ready[wname].is_set():
                                 stats.stall_events += 1
-                                loader.ready[wname].wait(timeout=60.0)
+                            loader.wait_ready(wname, op.index)
                             full = loader.assembled.get(wname) \
                                 if cache is not None else None
                         if full is None:
@@ -477,10 +531,7 @@ class StreamingExecutor:
                                 got = loader.arrived.pop(wname, [])
                             if len(got) < len(host_chunks[wname]):  # plan miss
                                 for c in host_chunks[wname][len(got):]:
-                                    got.append(
-                                        (jax.device_put(c[0]), float(c[1]))
-                                        if isinstance(c, tuple)
-                                        else jax.device_put(c))
+                                    got.append(put_chunk(c, self.device))
                             got = [g[0].astype(jnp.float32) * g[1]
                                    if isinstance(g, tuple) else g for g in got]
                             full = got[0] if len(got) == 1 else \
@@ -516,8 +567,11 @@ class StreamingExecutor:
         finally:
             stats.exec_s += time.perf_counter() - t1
         loader.join(timeout=10.0)
+        loader.check()
         stats.cache_hits += loader.hits
         stats.cache_misses += loader.misses
+        stats.streamed_chunks += loader.streamed_chunks
+        stats.streamed_bytes += loader.streamed_bytes
         stats.peak_bytes = max(stats.residency, default=0)
         stats.avg_bytes = float(np.mean(stats.residency)) \
             if stats.residency else 0
@@ -542,8 +596,9 @@ class PreloadExecutor:
 
     def __init__(self, model: HostModel, disk_bw: float = 0.0,
                  cache: Optional[WeightCache] = None,
-                 cache_key: Optional[str] = None):
+                 cache_key: Optional[str] = None, device=None):
         self.model = model
+        self.device = device
         self.disk_bw = disk_bw
         self.cache = cache
         self.cache_key = cache_key or model.graph.name
@@ -568,7 +623,8 @@ class PreloadExecutor:
             time.sleep(sum(m.host_weights[w].nbytes for w in missing)
                        / self.disk_bw)
         for w in missing:
-            dev[w] = jax.device_put(m.host_weights[w])
+            dev[w] = jax.device_put(m.host_weights[w], self.device)
+            stats.preloaded_bytes += int(m.host_weights[w].nbytes)
             if cache is not None and not cache.put(
                     (key, w, "w"), dev[w], m.host_weights[w].nbytes, pin=True):
                 transient += int(m.host_weights[w].nbytes)
@@ -576,7 +632,7 @@ class PreloadExecutor:
             v.block_until_ready()
         stats.init_s = time.perf_counter() - t0
 
-        regs = {"tokens": jax.device_put(tokens)}
+        regs = {"tokens": jax.device_put(tokens, self.device)}
         t1 = time.perf_counter()
         for op in m.graph.ops:
             warr = dev[op.weights[0]] if op.weights else None

@@ -21,25 +21,6 @@ from jax import numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-compat ``shard_map``.
-
-    jax >= 0.6 exposes ``jax.shard_map`` with a ``check_vma`` kwarg; older
-    releases only have ``jax.experimental.shard_map.shard_map`` whose
-    equivalent kwarg is ``check_rep``. Model code must not care which jax
-    is installed, so it goes through this shim.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            pass  # pre-check_vma signature; fall through to experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 @dataclass(frozen=True)
 class ParamSpec:
     shape: tuple

@@ -17,9 +17,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams", None)
-
 NEG_INF = -1e30
 
 
@@ -103,11 +100,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kr = k.transpose(0, 2, 1, 3).reshape(b * hkv, sk, hd)
     vr = v.transpose(0, 2, 1, 3).reshape(b * hkv, sk, hd)
 
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, bq=bq, bkv=bkv, nk=nk,
                           causal=causal, window=window),
@@ -127,6 +119,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qr, kr, vr)
     return out.reshape(b, hq, sq, hd).transpose(0, 2, 1, 3)

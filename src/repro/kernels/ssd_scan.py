@@ -21,9 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams", None)
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, state_ref, *,
             q: int):
@@ -89,11 +86,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     br = b.reshape(bsz, s, n)
     cr = c.reshape(bsz, s, n)
 
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-
     y = pl.pallas_call(
         functools.partial(_kernel, q=q),
         grid=(bsz * h, nc),
@@ -109,6 +101,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bsz * h, s, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(xr, dtr, ar, br, cr, dr)
     return y.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)

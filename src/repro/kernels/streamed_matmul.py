@@ -19,9 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams", None)
-
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     @pl.when(pl.program_id(2) == 0)
@@ -58,11 +55,6 @@ def streamed_matmul(a: jax.Array, b: jax.Array, *, block_m: int = 256,
     nk = k // bk
     grid = (m // bm, n // bn, nk)
 
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
     return pl.pallas_call(
         functools.partial(_kernel, nk=nk),
         grid=grid,
@@ -74,5 +66,6 @@ def streamed_matmul(a: jax.Array, b: jax.Array, *, block_m: int = 256,
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, b)

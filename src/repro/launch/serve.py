@@ -66,6 +66,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.core.streaming import HostModel, PreloadExecutor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.batcher import BatcherConfig
 from repro.serving.clock import SimClock
 from repro.serving.config import add_serve_config_flags, \
@@ -168,6 +169,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.replicas > 1 and not args.online:
         ap.error("--replicas needs --online (the Router replays a trace)")
+    enable_compile_cache()
 
     names = args.models.split(",")
     mix = None

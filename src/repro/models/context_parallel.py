@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, RunConfig
-from repro.distributed.sharding import MeshEnv, shard_map
+from repro.distributed.sharding import MeshEnv
 from repro.models import attention as attn
 from repro.models.layers import apply_norm
 from repro.models.transformer import embed_tokens, logits_fn
@@ -127,7 +127,7 @@ def cp_prefill(cfg: ModelConfig, run: RunConfig, env: MeshEnv, params,
         dsize *= mesh.shape[a]
     batch_axes = data_axes if data_axes and b % dsize == 0 else ()
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P_(batch_axes or None, "model", None), bs),
         out_specs=P_(batch_axes or None, "model", None),

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import MeshEnv, ParamSpec, shard_map
+from repro.distributed.sharding import MeshEnv, ParamSpec
 from repro.models.layers import activation
 
 
@@ -163,7 +163,7 @@ def apply_moe_shardmap(cfg: ModelConfig, p: dict, x: jax.Array, env: MeshEnv):
         return y.astype(x_loc.dtype), aux
 
     batch_spec = P_(data_axes if data_axes else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P_(*batch_spec, None, None), P_(None, None),
                   P_(None, None, "model"),

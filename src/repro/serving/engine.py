@@ -113,7 +113,8 @@ from repro.core.opg import OPGProblem
 from repro.core.plan import MultiModelPlan, OverlapPlan, plan_multi_model
 from repro.core.solver import SolverConfig, solve
 from repro.core.streaming import (ExecState, HostModel, PreloadExecutor,
-                                  RunStats, StreamingExecutor, chunk_rows)
+                                  RunStats, StreamingExecutor, chunk_rows,
+                                  put_chunk)
 from repro.serving.batcher import (Batch, BatcherConfig, can_join, make_batch,
                                    split_batch_result)
 from repro.serving.clock import MonotonicClock
@@ -449,6 +450,22 @@ class ServeSession:
                 self.stream.wait_for_push()
 
 
+class _Prefetcher(threading.Thread):
+    """Prefetch worker that keeps the exception it hit in ``error`` for
+    ``_stop_prefetch`` to re-raise on the serving thread."""
+
+    def __init__(self, fn: Callable, args: tuple):
+        super().__init__(daemon=True)
+        self.fn, self.args = fn, args
+        self.error: Optional[Exception] = None
+
+    def run(self):
+        try:
+            self.fn(*self.args)
+        except Exception as e:  # noqa: BLE001 — re-raised on join
+            self.error = e
+
+
 class ServingEngine:
     def __init__(self, *, policy: str = "stream", chunk_bytes: int = 1 << 20,
                  m_peak: int = 256 << 20, hw: Optional[HWSpec] = None,
@@ -464,12 +481,16 @@ class ServingEngine:
                  kv_seq_tokens: int = 0,
                  kv_target_seqs: int = 4,
                  arena: bool = False,
-                 log_cap: int = 10000):
+                 log_cap: int = 10000,
+                 device=None):
         assert policy in ("stream", "preload")
         self.policy = policy
         self.chunk_bytes = chunk_bytes
         self.m_peak = m_peak
-        self.hw = hw or HWSpec.cpu_calibrated()
+        # every weight, chunk and input goes to `device` (None = JAX's
+        # default device); the planner's constants are that device's
+        self.device = device
+        self.hw = hw or HWSpec.for_device(device or jax.devices()[0])
         self.disk_bw = disk_bw
         self.solver_cfg = solver_cfg
         self.budget_bytes = budget_bytes
@@ -637,10 +658,11 @@ class ServingEngine:
             if self.policy == "stream":
                 ex = StreamingExecutor(self.models[name], self.plans[name],
                                        disk_bw=self.disk_bw, cache=self.cache,
-                                       cache_key=name)
+                                       cache_key=name, device=self.device)
             else:
                 ex = PreloadExecutor(self.models[name], disk_bw=self.disk_bw,
-                                     cache=self.cache, cache_key=name)
+                                     cache=self.cache, cache_key=name,
+                                     device=self.device)
             self._executors[name] = ex
         return ex
 
@@ -840,8 +862,7 @@ class ServingEngine:
                     return False
             if stop.is_set():
                 return False
-            arr = (jax.device_put(host[0]), float(host[1])) \
-                if isinstance(host, tuple) else jax.device_put(host)
+            arr = put_chunk(host, self.device)
             if cache.put(key, arr, nbytes_if_load, pin=True):
                 pinned.append(key)
                 used += nbytes_if_load
@@ -887,13 +908,12 @@ class ServingEngine:
                         lookahead_ops: Optional[int] = None):
         limit = self._prefetch_limit(current)
         stop = threading.Event()
-        th = threading.Thread(target=self._protect_and_prefetch,
-                              args=(target, limit, stop, lookahead_ops),
-                              daemon=True)
+        th = _Prefetcher(self._protect_and_prefetch,
+                         (target, limit, stop, lookahead_ops))
         th.start()
         return th, stop
 
-    def _stop_prefetch(self, th: Optional[threading.Thread],
+    def _stop_prefetch(self, th: Optional["_Prefetcher"],
                        stop: Optional[threading.Event]):
         if th is not None:
             # the stop flag bounds the join: the thread checks it before
@@ -901,6 +921,8 @@ class ServingEngine:
             # and _release_protection cannot orphan a live pin list
             stop.set()
             th.join()
+            if th.error is not None:
+                raise RuntimeError("prefetch thread failed") from th.error
 
     def _release_protection(self, name: str):
         for key in self._protected.pop(name, []):

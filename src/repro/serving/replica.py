@@ -6,10 +6,11 @@ the Router pushes routed requests into the replica's ``RequestStream``
 inbox and advances the replica's ``ReplicaClock`` to the session's
 ``next_time()`` before each step — N replicas interleave as one
 deterministic discrete-event simulation on a shared virtual timeline (no
-threads, no real sleeps). Each replica is notionally pinned to a device:
-``jax.devices()[rid % n_devices]`` — on a one-device host every replica
-shares it (the simulated-fleet mode the tests and benchmarks run in);
-on a multi-accelerator host the modulo spreads them.
+threads, no real sleeps). Each replica is pinned to a device,
+``jax.devices()[rid % n_devices]``, where its engine places every weight,
+chunk and input — on a one-device host every replica shares it (the
+simulated-fleet mode the tests and benchmarks run in); on a
+multi-accelerator host the modulo spreads them, one replica per chip.
 
 ``FaultPlan`` is the injectable failure schedule, stamped in virtual
 seconds on the ROUTER's watermark clock:
@@ -124,8 +125,10 @@ class Replica:
                  engine: Optional[ServingEngine] = None, **engine_kw):
         self.rid = rid
         self.name = f"r{rid}"
+        devs = jax.devices()
+        self.device = devs[rid % len(devs)]
         self.engine = engine if engine is not None \
-            else ServingEngine(**engine_kw)
+            else ServingEngine(device=self.device, **engine_kw)
         self.clock = clock or ReplicaClock()
         self.inbox = RequestStream()
         self.session: Optional[ServeSession] = None
@@ -133,8 +136,6 @@ class Replica:
         # by routing decisions)
         self.dead = False
         self.wedged = False
-        devs = jax.devices()
-        self.device = devs[rid % len(devs)]
         # (finish_t, model, charged_s) per completed batch — the
         # straggler detector's per-replica latency feed. Ring-buffered
         # (engine log_cap): the detector only reads the latest entries,

@@ -637,6 +637,11 @@ class WeightCache:
         with self._lock:
             return list(self._entries)
 
+    def items(self):
+        """Snapshot of (key, value) for every resident entry."""
+        with self._lock:
+            return [(k, e.value) for k, e in self._entries.items()]
+
     def stats_snapshot(self) -> dict:
         """Atomic copy of the ledger counters — diff two snapshots to
         prove what a critical section (e.g. the engine's online plan
