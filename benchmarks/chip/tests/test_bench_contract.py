@@ -1,0 +1,110 @@
+"""BENCHMARK.json against the rules of the benchmark format, and every name in
+it resolved to its files: configuration, traffic mix, metric reader."""
+import json
+import re
+from pathlib import Path
+
+import pytest
+
+HERE = Path(__file__).resolve().parents[1]
+REPO = HERE.parents[1]
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def test_top_level_keys_and_command():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert BENCH["paths"] == ["benchmarks/chip"]
+    for word in BENCH["command"]:
+        assert not word.startswith("/") and ".." not in word
+    assert (REPO / BENCH["command"][1]).is_file()
+
+
+def test_run_seconds_fits_the_check_with_24_cells():
+    rs = BENCH["run_seconds"]
+    assert 1 <= rs <= 51
+    assert (2 + 14 * 24) * (rs + 60) + 24 * 2 * 90 + 1200 <= 43200
+
+
+def test_names_units_and_keys():
+    for c in BENCH["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"])
+    for w in BENCH["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+    for m in BENCH["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
+                                          "source"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in BENCH["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+                                          "layer", "moves"}
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    all_names = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
+    assert len(all_names) == len(set(all_names))
+    assert len(CELLS) == len(set(CELLS))
+
+
+def test_rooflines_and_mfu_are_percent():
+    for m in BENCH["per_layer"]:
+        if m["name"].endswith("_roofline") or "mfu" in m["name"]:
+            assert m["unit"] == "%"
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_resolves_to_its_files(cell):
+    import harness
+    c = harness.load_cell(cell)
+    assert c.config["models"] and 0 < c.config["check"]["row_err_median"] \
+        < c.config["check"]["row_err_max"]
+    cfg = {x["name"]: x for x in BENCH["configs"]}[
+        {w["name"]: w for w in BENCH["workloads"]}[cell]["config"]]
+    assert (REPO / cfg["file"]).is_file()
+    assert cfg["file"].startswith("benchmarks/chip/")
+    for m in c.per_layer:
+        assert callable(harness.reader(m["name"]))
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_reports_setup_another_e2e_and_a_layer(cell):
+    import harness
+    c = harness.load_cell(cell)
+    names = {m["name"] for m in c.end_to_end}
+    assert "setup_s" in names and len(names) >= 2
+    assert c.per_layer
+
+
+def test_every_per_layer_metric_moves_a_metric_its_cells_report():
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    for m in BENCH["per_layer"]:
+        moved = e2e[m["moves"]]
+        for cell in m.get("workloads", CELLS):
+            assert cell in CELLS
+            assert "workloads" not in moved or cell in moved["workloads"], \
+                (m["name"], cell)
+
+
+def test_one_layer_name_per_layer():
+    layers = {m["layer"] for m in BENCH["per_layer"]}
+    assert all("\n" not in x and len(x) <= 200 for x in layers)
+
+
+def test_configs_are_used_and_keep_published_widths():
+    used = {w["config"] for w in BENCH["workloads"]}
+    for c in BENCH["configs"]:
+        assert c["name"] in used
+        cfg = json.loads((REPO / c["file"]).read_text())
+        assert cfg["reduced"] == c["reduced"]
+        for arch in cfg["archs"].values():
+            assert arch["hidden_size"] in (2048, 2560)
+            assert arch["num_layers"] in (24, 32)
