@@ -92,7 +92,7 @@ class _SyntheticExecutor:
     def __init__(self, name: str):
         self.name = name
 
-    def run(self, tokens) -> RunStats:
+    def run(self, tokens, batch: int = -1) -> RunStats:
         return RunStats(init_s=0.0, exec_s=EXEC_S, peak_bytes=1 << 20,
                         avg_bytes=float(1 << 20), residency=[1 << 20],
                         model=self.name, result=None)

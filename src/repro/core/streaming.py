@@ -24,6 +24,11 @@ multi-model workloads hit device-resident weights instead of re-streaming
 them from host/disk. Cache keys are ``(cache_key, weight, chunk_index)``
 for in-flight chunks and ``(cache_key, weight, "w")`` for assembled
 weights; the executor that assembles a weight consumes its chunk entries.
+
+Each phase of a run is a ``jax.profiler.TraceAnnotation`` named
+``flashmem.exec.*`` (compute thread) or ``flashmem.loader.*`` (load
+thread), tagged with the model and the engine's batch id, on the
+profiler's clock; ``RunStats`` sums the same phases in seconds.
 """
 from __future__ import annotations
 
@@ -213,6 +218,17 @@ class RunStats:
     preloaded_bytes: int = 0     # whole weights device_put before the op loop
     streamed_chunks: int = 0     # chunks the loader device_put during it
     streamed_bytes: int = 0
+    # where exec_s went, summed over the run's segments: waiting for the
+    # loader (flashmem.exec.wait_weight), assembling chunks into weights
+    # (flashmem.exec.assemble), the final device sync (flashmem.exec.sync),
+    # and the rest of the op loop, host dispatch of its ops_run ops
+    stall_s: float = 0.0
+    assemble_s: float = 0.0
+    sync_s: float = 0.0
+    dispatch_s: float = 0.0
+    ops_run: int = 0
+    # loader thread: time in device_put + pinned check-in
+    put_s: float = 0.0
     result: Any = None
 
     @property
@@ -264,11 +280,14 @@ class _Loader(threading.Thread):
     so LRU pressure cannot drop bytes that are about to be consumed.
 
     An exception on this thread (e.g. HBM exhausted in a device_put) is
-    kept in ``error`` and re-raised by ``check`` on the compute thread."""
+    kept in ``error`` and re-raised by ``check`` on the compute thread.
+
+    ``batch`` is the engine's id of the batch the run serves; it tags the
+    thread's spans."""
 
     def __init__(self, plan: OverlapPlan, host_chunks: Dict[str, list],
                  disk_bw: float, cache: Optional[WeightCache] = None,
-                 cache_key: str = "", device=None):
+                 cache_key: str = "", device=None, batch: int = -1):
         super().__init__(daemon=True)
         self.plan = plan
         self.host_chunks = host_chunks
@@ -276,6 +295,7 @@ class _Loader(threading.Thread):
         self.cache = cache
         self.cache_key = cache_key
         self.device = device
+        self.batch = batch
         self.error: Optional[Exception] = None
         # op at whose load tasks the plan completes each weight; a weight
         # absent here, or due after its consumer, is a plan miss
@@ -298,6 +318,7 @@ class _Loader(threading.Thread):
         self.misses = 0
         self.streamed_chunks = 0
         self.streamed_bytes = 0
+        self.put_s = 0.0
         self.lock = threading.Lock()
 
     def allow_through(self, op_index: int):
@@ -319,6 +340,7 @@ class _Loader(threading.Thread):
             self.misses += 1
         if self.disk_bw > 0:
             time.sleep(nbytes / self.disk_bw)
+        t = time.perf_counter()
         arr = put_chunk(chunk, self.device)
         self.streamed_chunks += 1
         self.streamed_bytes += int(nbytes)
@@ -328,6 +350,7 @@ class _Loader(threading.Thread):
                 with self.lock:
                     self.uncached_bytes[w] = \
                         self.uncached_bytes.get(w, 0) + int(nbytes)
+        self.put_s += time.perf_counter() - t
         return arr
 
     def run(self):
@@ -355,30 +378,36 @@ class _Loader(threading.Thread):
         self.check()
 
     def _load_all(self):
+        meta = {"model": self.cache_key, "batch": self.batch}
         for l in sorted(self.plan.loads):
             # the load queue may run at most one op "ahead window" — tasks
             # for op l are issued once compute reaches op l (the plan already
             # encodes lookahead via which op the task is assigned to)
             ev = self.gate.get(l)
-            if ev is not None:
-                ev.wait()
+            if ev is not None and not ev.is_set():
+                with jax.profiler.TraceAnnotation("flashmem.loader.gate",
+                                                  **meta):
+                    ev.wait()
             for task in self.plan.loads[l]:
                 w = task.weight
                 if w in self.assembled or self.ready[w].is_set():
                     continue
-                if self.cache is not None and w not in self.arrived:
-                    full = self.cache.acquire((self.cache_key, w, "w"))
-                    if full is not None:               # assembled on device
-                        self.hits += 1
-                        self.assembled[w] = full
-                        self.ready[w].set()
-                        continue
-                    self.misses += 1
-                hcs = self.host_chunks[w]
-                for ci in range(task.chunk_lo, min(task.chunk_hi, len(hcs))):
-                    arr = self._load_chunk(w, ci, hcs[ci])
-                    with self.lock:
-                        self.arrived.setdefault(w, []).append(arr)
+                with jax.profiler.TraceAnnotation("flashmem.loader.task",
+                                                  **meta):
+                    if self.cache is not None and w not in self.arrived:
+                        full = self.cache.acquire((self.cache_key, w, "w"))
+                        if full is not None:           # assembled on device
+                            self.hits += 1
+                            self.assembled[w] = full
+                            self.ready[w].set()
+                            continue
+                        self.misses += 1
+                    hcs = self.host_chunks[w]
+                    for ci in range(task.chunk_lo,
+                                    min(task.chunk_hi, len(hcs))):
+                        arr = self._load_chunk(w, ci, hcs[ci])
+                        with self.lock:
+                            self.arrived.setdefault(w, []).append(arr)
                 if len(self.arrived.get(w, ())) >= len(hcs):
                     self.ready[w].set()
 
@@ -398,6 +427,7 @@ class ExecState:
     regs: Dict[str, Any]
     op_idx: int = 0
     done: bool = False
+    batch: int = -1                 # the engine's batch id, for spans
 
 
 class StreamingExecutor:
@@ -441,9 +471,11 @@ class StreamingExecutor:
                 for lst in loader.arrived.values() for c in lst)
         return sum(int(v.nbytes) for v in dev.values()) + inflight
 
-    def begin(self, tokens: np.ndarray) -> ExecState:
+    def begin(self, tokens: np.ndarray, batch: int = -1) -> ExecState:
         """Preload phase + loader start: everything up to the op loop.
-        Returns the resumable run state ``advance`` consumes."""
+        Returns the resumable run state ``advance`` consumes. ``batch``
+        is the engine's id of the batch, carried by every span of the
+        run."""
         m, plan, cache, key = self.model, self.plan, self.cache, self.cache_key
         stats = RunStats(model=key)
         host_chunks = {w: chunk_rows(m.host_weights[w], plan.chunk_bytes)
@@ -453,41 +485,44 @@ class StreamingExecutor:
                 w: [quantize_chunk(c) if c.nbytes > 4096 else c for c in lst]
                 for w, lst in host_chunks.items()}
 
-        dev: Dict[str, jax.Array] = {}
-        transient: Dict[str, int] = {}    # on-device but pool-rejected bytes
-        t0 = time.perf_counter()
-        for w in plan.preload:
-            arr = None
-            if cache is not None:
-                arr = cache.acquire((key, w, "w"))
-                if arr is not None:
-                    stats.cache_hits += 1
-                else:
-                    stats.cache_misses += 1
-            if arr is None:
-                nbytes = m.host_weights[w].nbytes
-                if self.disk_bw > 0:
-                    time.sleep(nbytes / self.disk_bw)
-                arr = jax.device_put(m.host_weights[w], self.device)
-                stats.preloaded_bytes += int(nbytes)
-                if cache is not None and not cache.put((key, w, "w"), arr,
-                                                       nbytes, pin=True):
-                    transient[w] = int(nbytes)
-            dev[w] = arr
-        for v in dev.values():
-            v.block_until_ready()
-        stats.init_s = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("flashmem.exec.begin", model=key,
+                                          batch=batch):
+            dev: Dict[str, jax.Array] = {}
+            transient: Dict[str, int] = {}  # on-device but pool-rejected
+            t0 = time.perf_counter()
+            for w in plan.preload:
+                arr = None
+                if cache is not None:
+                    arr = cache.acquire((key, w, "w"))
+                    if arr is not None:
+                        stats.cache_hits += 1
+                    else:
+                        stats.cache_misses += 1
+                if arr is None:
+                    nbytes = m.host_weights[w].nbytes
+                    if self.disk_bw > 0:
+                        time.sleep(nbytes / self.disk_bw)
+                    arr = jax.device_put(m.host_weights[w], self.device)
+                    stats.preloaded_bytes += int(nbytes)
+                    if cache is not None and not cache.put(
+                            (key, w, "w"), arr, nbytes, pin=True):
+                        transient[w] = int(nbytes)
+                dev[w] = arr
+            for v in dev.values():
+                v.block_until_ready()
+            stats.init_s = time.perf_counter() - t0
 
-        loader = _Loader(plan, host_chunks, self.disk_bw, cache=cache,
-                         cache_key=key, device=self.device)
-        if self.gate_loads:
-            loader.gate = {l: threading.Event() for l in plan.loads}
-        loader.start()
+            loader = _Loader(plan, host_chunks, self.disk_bw, cache=cache,
+                             cache_key=key, device=self.device, batch=batch)
+            if self.gate_loads:
+                loader.gate = {l: threading.Event() for l in plan.loads}
+            loader.start()
 
-        regs = {"tokens": jax.device_put(tokens, self.device)}
-        return ExecState(tokens=tokens, stats=stats, host_chunks=host_chunks,
-                         dev=dev, transient=transient, loader=loader,
-                         regs=regs)
+            regs = {"tokens": jax.device_put(tokens, self.device)}
+            return ExecState(tokens=tokens, stats=stats,
+                             host_chunks=host_chunks, dev=dev,
+                             transient=transient, loader=loader, regs=regs,
+                             batch=batch)
 
     def advance(self, st: ExecState,
                 should_yield: Optional[Callable[[int], bool]] = None) -> bool:
@@ -506,72 +541,112 @@ class StreamingExecutor:
         loader, host_chunks = st.loader, st.host_chunks
         ops = m.graph.ops
         entry_idx = st.op_idx
+        meta = {"model": key, "batch": st.batch}
+        # this segment's dispatch time is what its wait, assembly and sync
+        # spans leave of it; those counters already hold earlier segments
+        timed = stats.stall_s + stats.assemble_s + stats.sync_s
         t1 = time.perf_counter()
-        try:
-            while st.op_idx < len(ops):
-                if (should_yield is not None and st.op_idx > entry_idx
-                        and should_yield(st.op_idx)):
-                    return False
-                op = ops[st.op_idx]
-                loader.allow_through(op.index)
-                warr = None
-                if op.weights:
-                    wname = op.weights[0]
-                    if wname not in dev:
-                        full = loader.assembled.get(wname) \
-                            if cache is not None else None
-                        if full is None:
-                            if not loader.ready[wname].is_set():
-                                stats.stall_events += 1
-                            loader.wait_ready(wname, op.index)
+        with jax.profiler.TraceAnnotation("flashmem.exec.ops", **meta):
+            try:
+                while st.op_idx < len(ops):
+                    if (should_yield is not None and st.op_idx > entry_idx
+                            and should_yield(st.op_idx)):
+                        return False
+                    op = ops[st.op_idx]
+                    loader.allow_through(op.index)
+                    warr = None
+                    if op.weights:
+                        wname = op.weights[0]
+                        if wname not in dev:
                             full = loader.assembled.get(wname) \
                                 if cache is not None else None
-                        if full is None:
-                            with loader.lock:
-                                got = loader.arrived.pop(wname, [])
-                            if len(got) < len(host_chunks[wname]):  # plan miss
-                                for c in host_chunks[wname][len(got):]:
-                                    got.append(put_chunk(c, self.device))
-                            got = [g[0].astype(jnp.float32) * g[1]
-                                   if isinstance(g, tuple) else g for g in got]
-                            full = got[0] if len(got) == 1 else \
-                                jnp.concatenate(got, axis=0)
+                            if full is None:
+                                if loader.ready[wname].is_set():
+                                    loader.check()
+                                else:
+                                    stats.stall_events += 1
+                                    t = time.perf_counter()
+                                    with jax.profiler.TraceAnnotation(
+                                            "flashmem.exec.wait_weight",
+                                            **meta):
+                                        loader.wait_ready(wname, op.index)
+                                    stats.stall_s += time.perf_counter() - t
+                                full = loader.assembled.get(wname) \
+                                    if cache is not None else None
+                            if full is None:
+                                t = time.perf_counter()
+                                with jax.profiler.TraceAnnotation(
+                                        "flashmem.exec.assemble", **meta):
+                                    # `got` stays referenced until the next
+                                    # assembly or the end of this call:
+                                    # freeing the chunks as soon as their
+                                    # concatenate was dispatched made it
+                                    # block, and cost a streamed 2.7B batch
+                                    # 6 to 7% of its rate on a TPU v5e
+                                    with loader.lock:
+                                        got = loader.arrived.pop(wname, [])
+                                    # plan miss
+                                    if len(got) < len(host_chunks[wname]):
+                                        for c in host_chunks[wname][len(got):]:
+                                            got.append(
+                                                put_chunk(c, self.device))
+                                    got = [g[0].astype(jnp.float32) * g[1]
+                                           if isinstance(g, tuple) else g
+                                           for g in got]
+                                    full = got[0] if len(got) == 1 else \
+                                        jnp.concatenate(got, axis=0)
+                                    if cache is not None:
+                                        # chunk entries are consumed into
+                                        # the assembled weight; re-key so
+                                        # future runs hit it whole
+                                        for ci in range(
+                                                len(host_chunks[wname])):
+                                            cache.remove((key, wname, ci))
+                                        with loader.lock:
+                                            loader.uncached_bytes.pop(
+                                                wname, None)
+                                        if not cache.put(
+                                                (key, wname, "w"), full,
+                                                int(full.nbytes), pin=True):
+                                            transient[wname] = \
+                                                int(full.nbytes)
+                                stats.assemble_s += time.perf_counter() - t
+                            dev[wname] = full
+                        warr = dev[wname]
+                    st.regs = m.programs[op_tag(op.name)](st.regs, warr)
+                    for wname in op.weights:
+                        if self.last_use[wname] <= op.index:
+                            dev.pop(wname, None)
                             if cache is not None:
-                                # chunk entries are consumed into the
-                                # assembled weight; re-key so future runs
-                                # hit it whole
-                                for ci in range(len(host_chunks[wname])):
-                                    cache.remove((key, wname, ci))
-                                with loader.lock:
-                                    loader.uncached_bytes.pop(wname, None)
-                                if not cache.put((key, wname, "w"), full,
-                                                 int(full.nbytes), pin=True):
-                                    transient[wname] = int(full.nbytes)
-                        dev[wname] = full
-                    warr = dev[wname]
-                st.regs = m.programs[op_tag(op.name)](st.regs, warr)
-                for wname in op.weights:
-                    if self.last_use[wname] <= op.index:
-                        dev.pop(wname, None)
-                        if cache is not None:
-                            cache.release((key, wname, "w"))
-                            transient.pop(wname, None)
-                stats.residency.append(
-                    self._residency(dev, loader, transient))
-                st.op_idx += 1
-            # final segment: the device sync belongs in the timed region —
-            # the op loop largely enqueues async work, so exec_s must cover
-            # actual execution, not just dispatch (pre-refactor semantics)
-            jax.tree.map(lambda x: x.block_until_ready()
-                         if hasattr(x, "block_until_ready") else x, st.regs)
-        finally:
-            stats.exec_s += time.perf_counter() - t1
+                                cache.release((key, wname, "w"))
+                                transient.pop(wname, None)
+                    stats.residency.append(
+                        self._residency(dev, loader, transient))
+                    st.op_idx += 1
+                # final segment: the device sync belongs in the timed
+                # region — the op loop largely enqueues async work, so
+                # exec_s must cover actual execution, not just dispatch
+                # (pre-refactor semantics)
+                t = time.perf_counter()
+                with jax.profiler.TraceAnnotation("flashmem.exec.sync",
+                                                  **meta):
+                    jax.tree.map(lambda x: x.block_until_ready()
+                                 if hasattr(x, "block_until_ready") else x,
+                                 st.regs)
+                stats.sync_s += time.perf_counter() - t
+            finally:
+                seg = time.perf_counter() - t1
+                stats.exec_s += seg
+                stats.dispatch_s += seg - (stats.stall_s + stats.assemble_s
+                                           + stats.sync_s - timed)
+                stats.ops_run += st.op_idx - entry_idx
         loader.join(timeout=10.0)
         loader.check()
         stats.cache_hits += loader.hits
         stats.cache_misses += loader.misses
         stats.streamed_chunks += loader.streamed_chunks
         stats.streamed_bytes += loader.streamed_bytes
+        stats.put_s += loader.put_s
         stats.peak_bytes = max(stats.residency, default=0)
         stats.avg_bytes = float(np.mean(stats.residency)) \
             if stats.residency else 0
@@ -579,9 +654,9 @@ class StreamingExecutor:
         st.done = True
         return True
 
-    def run(self, tokens: np.ndarray) -> RunStats:
+    def run(self, tokens: np.ndarray, batch: int = -1) -> RunStats:
         """One-shot, non-preemptible execution (the pre-PR entry point)."""
-        st = self.begin(tokens)
+        st = self.begin(tokens, batch)
         self.advance(st)
         return st.stats
 
@@ -603,36 +678,40 @@ class PreloadExecutor:
         self.cache = cache
         self.cache_key = cache_key or model.graph.name
 
-    def run(self, tokens: np.ndarray) -> RunStats:
+    def run(self, tokens: np.ndarray, batch: int = -1) -> RunStats:
         m, cache, key = self.model, self.cache, self.cache_key
         stats = RunStats(model=key)
-        dev: Dict[str, jax.Array] = {}
-        transient = 0                      # on-device but pool-rejected bytes
-        t0 = time.perf_counter()
-        missing = []
-        for w, arr in m.host_weights.items():
-            cached = cache.acquire((key, w, "w")) if cache is not None else None
-            if cached is not None:
-                stats.cache_hits += 1
-                dev[w] = cached
-            else:
-                if cache is not None:
-                    stats.cache_misses += 1
-                missing.append(w)
-        if self.disk_bw > 0 and missing:
-            time.sleep(sum(m.host_weights[w].nbytes for w in missing)
-                       / self.disk_bw)
-        for w in missing:
-            dev[w] = jax.device_put(m.host_weights[w], self.device)
-            stats.preloaded_bytes += int(m.host_weights[w].nbytes)
-            if cache is not None and not cache.put(
-                    (key, w, "w"), dev[w], m.host_weights[w].nbytes, pin=True):
-                transient += int(m.host_weights[w].nbytes)
-        for v in dev.values():
-            v.block_until_ready()
-        stats.init_s = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("flashmem.exec.begin", model=key,
+                                          batch=batch):
+            dev: Dict[str, jax.Array] = {}
+            transient = 0                  # on-device but pool-rejected bytes
+            t0 = time.perf_counter()
+            missing = []
+            for w, arr in m.host_weights.items():
+                cached = cache.acquire((key, w, "w")) \
+                    if cache is not None else None
+                if cached is not None:
+                    stats.cache_hits += 1
+                    dev[w] = cached
+                else:
+                    if cache is not None:
+                        stats.cache_misses += 1
+                    missing.append(w)
+            if self.disk_bw > 0 and missing:
+                time.sleep(sum(m.host_weights[w].nbytes for w in missing)
+                           / self.disk_bw)
+            for w in missing:
+                dev[w] = jax.device_put(m.host_weights[w], self.device)
+                stats.preloaded_bytes += int(m.host_weights[w].nbytes)
+                if cache is not None and not cache.put(
+                        (key, w, "w"), dev[w], m.host_weights[w].nbytes,
+                        pin=True):
+                    transient += int(m.host_weights[w].nbytes)
+            for v in dev.values():
+                v.block_until_ready()
+            stats.init_s = time.perf_counter() - t0
 
-        regs = {"tokens": jax.device_put(tokens, self.device)}
+            regs = {"tokens": jax.device_put(tokens, self.device)}
         t1 = time.perf_counter()
         for op in m.graph.ops:
             warr = dev[op.weights[0]] if op.weights else None

@@ -283,6 +283,9 @@ class _RunningBatch:
     predicted_s: float = 0.0
     cold_bytes: int = 0
     decode_tokens: int = 0
+    # the engine's running batch number (batch_log.total at first start):
+    # every flashmem.* span of the batch, on any thread, carries it
+    batch_id: int = -1
 
     def remaining_s(self, cost: BatchLatencyEstimator) -> float:
         if self.state is None:
@@ -300,6 +303,29 @@ class _RunningBatch:
         """Priority-weighted resume key (same scale as a queue head's)."""
         return weighted_urgency(self.effective_deadline(cost), now,
                                 self.priority)
+
+
+class _HostPhase:
+    """The serving loop's open ``flashmem.engine.*`` span. The loop is a
+    generator and a span must not stay open across a yield, so the loop
+    starts and ends it by hand: ``start`` ends the open span first, and
+    ``ServeSession.step`` ends whatever is open once the loop yields,
+    returns or raises."""
+
+    def __init__(self):
+        self._span = None
+
+    def start(self, name: str, **meta):
+        self.end()
+        self._span = jax.profiler.TraceAnnotation(name, **meta)
+        self._span.__enter__()
+
+    def end(self, **meta):
+        if self._span is not None:
+            if meta:
+                self._span.set_metadata(**meta)
+            self._span.__exit__(None, None, None)
+            self._span = None
 
 
 class ServeSession:
@@ -348,6 +374,7 @@ class ServeSession:
         self.idle = False           # last step yielded "idle"
         self.steps = 0              # step() calls that advanced the loop —
                                     # the trace-scale O(events) check
+        self.phase = _HostPhase()   # the loop's open host span
         self._gen = engine._serve_loop(
             self, stream, clock, batcher=config.batcher,
             scheduler=config.scheduler,
@@ -371,6 +398,8 @@ class ServeSession:
             self.done = True
             self.idle = False
             return ("done", None)
+        finally:
+            self.phase.end()
         self.idle = kind == "idle"
         return (kind, payload)
 
@@ -905,11 +934,17 @@ class ServingEngine:
                     return
 
     def _start_prefetch(self, target: str, current: str,
-                        lookahead_ops: Optional[int] = None):
+                        lookahead_ops: Optional[int] = None,
+                        batch: int = -1):
         limit = self._prefetch_limit(current)
         stop = threading.Event()
-        th = _Prefetcher(self._protect_and_prefetch,
-                         (target, limit, stop, lookahead_ops))
+
+        def work():
+            with jax.profiler.TraceAnnotation("flashmem.prefetch",
+                                              model=target, batch=batch):
+                self._protect_and_prefetch(target, limit, stop,
+                                           lookahead_ops)
+        th = _Prefetcher(work, ())
         th.start()
         return th, stop
 
@@ -1590,6 +1625,9 @@ class ServingEngine:
                 finish_replan(now)
 
         while True:
+            # host span of one scheduling pass, tagged with the batch it
+            # picks just before that runs
+            ses.phase.start("flashmem.engine.schedule")
             now = clock.now()
             for r in stream.poll(now):
                 admit(r, now)
@@ -1729,7 +1767,10 @@ class ServingEngine:
                     # the whole fused execution must land by the tightest
                     # member deadline (resolved through the SLO config)
                     deadline_s=min(deadline_of(r) for r in batch.requests),
-                    priority=batch.priority)
+                    priority=batch.priority,
+                    # batch_log gains this batch's entry below, before
+                    # any other batch is formed
+                    batch_id=self.batch_log.total)
             prefetcher = pf_stop = None
             target, speculative = self._pick_prefetch_target(
                 pending, stream, name, sched, urg)
@@ -1738,7 +1779,7 @@ class ServingEngine:
                 prefetcher, pf_stop = self._start_prefetch(
                     target, name,
                     lookahead_ops=speculative_lookahead_ops if speculative
-                    else None)
+                    else None, batch=item.batch_id)
             if not item.started:
                 item.t_start = clock.now()
                 self.batch_log.append((item.t_start, name, item.batch.size))
@@ -1791,19 +1832,22 @@ class ServingEngine:
                     # wait this batch out — never ping-pong between equals
                     return waiting_misses and d_best < _d
             ex = self._executor(name)
+            ses.phase.end(model=name, batch=item.batch_id)
             seg_real_t0 = time.perf_counter()
             if isinstance(ex, StreamingExecutor):
                 if item.state is None:
-                    item.state = ex.begin(item.batch.tokens)
+                    item.state = ex.begin(item.batch.tokens, item.batch_id)
                 ops_before = item.state.op_idx
                 done = ex.advance(item.state, yield_check)
                 frac = ((item.state.op_idx - ops_before)
                         / max(item.n_ops, 1))
                 stats = item.state.stats
             else:                    # preload executor: never preemptible
-                stats = ex.run(item.batch.tokens)
+                stats = ex.run(item.batch.tokens, item.batch_id)
                 done, frac = True, 1.0
             seg_real = time.perf_counter() - seg_real_t0
+            ses.phase.start("flashmem.engine.respond", model=name,
+                            batch=item.batch_id)
             item.charged_s += clock.tick(seg_real, name, frac=frac,
                                          batch_size=item.batch.size)
             if self.unified:
