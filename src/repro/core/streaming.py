@@ -8,6 +8,10 @@ Executors:
   * StreamingExecutor  — FlashMem: issues async device_put of the chunk
     tasks scheduled at each op (JAX's async dispatch = the independent DMA
     queue), assembles weights at first use, frees them after last use.
+    Plans count in chunks; the transfer unit is a slab, one put of each
+    contiguous run of a task's missing plain chunks. A streamed weight's
+    op is dispatched once its bytes have landed and the device has run
+    the ops before it, so queued work never holds HBM past the pool.
   * PreloadExecutor    — SmartMem/MNN-style: move+transform ALL weights,
     then run (init/exec split reporting).
   * Plans from plan_always_next / plan_same_op_type run through the same
@@ -22,8 +26,10 @@ Both executors can additionally be bound to a shared ``WeightCache``
 in/out of one budgeted device pool, so repeated requests and interleaved
 multi-model workloads hit device-resident weights instead of re-streaming
 them from host/disk. Cache keys are ``(cache_key, weight, chunk_index)``
-for in-flight chunks and ``(cache_key, weight, "w")`` for assembled
-weights; the executor that assembles a weight consumes its chunk entries.
+for in-flight chunks, ``(cache_key, weight, ("rows", lo, hi))`` for slabs
+of chunks ``lo..hi-1`` (no chunk probe returns one) and
+``(cache_key, weight, "w")`` for assembled weights; the executor that
+assembles a weight consumes its chunk and slab entries.
 
 Each phase of a run is a ``jax.profiler.TraceAnnotation`` named
 ``flashmem.exec.*`` (compute thread) or ``flashmem.loader.*`` (load
@@ -32,11 +38,12 @@ profiler's clock; ``RunStats`` sums the same phases in seconds.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -219,16 +226,20 @@ class RunStats:
     streamed_chunks: int = 0     # chunks the loader device_put during it
     streamed_bytes: int = 0
     # where exec_s went, summed over the run's segments: waiting for the
-    # loader (flashmem.exec.wait_weight), assembling chunks into weights
-    # (flashmem.exec.assemble), the final device sync (flashmem.exec.sync),
-    # and the rest of the op loop, host dispatch of its ops_run ops
+    # loader and for its puts to land (flashmem.exec.wait_weight),
+    # assembling chunks into weights (flashmem.exec.assemble), waiting for
+    # the device before streamed weights' ops and at the end
+    # (flashmem.exec.sync), and the rest of the op loop, host dispatch of
+    # its ops_run ops
     stall_s: float = 0.0
     assemble_s: float = 0.0
     sync_s: float = 0.0
     dispatch_s: float = 0.0
     ops_run: int = 0
-    # loader thread: time in device_put + pinned check-in
+    # loader thread: time in device_put + pinned check-in, and the
+    # device_puts it issued (one per slab or unslabbed chunk)
     put_s: float = 0.0
+    h2d_puts: int = 0
     result: Any = None
 
     @property
@@ -267,17 +278,82 @@ def put_chunk(chunk, device):
     return jax.device_put(chunk, device)
 
 
+# Largest slab. Back-to-back puts of float32 row slices landed in HBM on a
+# TPU v5e at 4.1 to 4.3 GB/s in 1 MiB slices, 13.0 to 14.0 in 4 MiB, 13.9
+# to 14.1 from 8 to 32 MiB, then fell: 13.7 to 13.9 at 40 to 64 MiB, 13.6
+# to 13.8 at 78 and 100 MiB. A longer run moves in equal slabs.
+SLAB_BYTES = 32 << 20
+
+
+def _wire_bytes(chunk) -> int:
+    """Bytes a host chunk (array or (int8, scale)) moves to the device."""
+    return int(chunk[0].nbytes if isinstance(chunk, tuple) else chunk.nbytes)
+
+
+def _stretches(chunks: list, lo: int, hi: int):
+    """Split chunks ``lo..hi-1`` into maximal stretches ``(a, b)`` whose
+    chunks are plain row views of one host array, each starting where the
+    one before ends, so a stretch can move as one slab. A quantised
+    ``(int8, scale)`` chunk, with its own scale, or any other chunk stands
+    alone."""
+    a, end = lo, None
+    for ci in range(lo, hi):
+        c = chunks[ci]
+        joins = False
+        if (isinstance(c, np.ndarray) and c.base is not None
+                and c.flags.c_contiguous):
+            addr = c.__array_interface__["data"][0]
+            prev = chunks[ci - 1]
+            joins = (ci > a and addr == end and c.base is prev.base
+                     and c.dtype == prev.dtype
+                     and c.shape[1:] == prev.shape[1:])
+            end = addr + c.nbytes
+        else:
+            end = None
+        if ci > a and not joins:
+            yield a, ci
+            a = ci
+    if lo < hi:
+        yield a, hi
+
+
+def _slab(chunks: list) -> np.ndarray:
+    """The rows of one of ``_stretches``' stretches as one view of their
+    host array: no host copy."""
+    first = chunks[0]
+    rows = sum(c.shape[0] for c in chunks)
+    return np.lib.stride_tricks.as_strided(
+        first, (rows,) + first.shape[1:], first.strides, writeable=False)
+
+
+class _Piece(NamedTuple):
+    """What arrived of a weight: one chunk, or one slab of ``chunks``
+    consecutive chunks, as its device value and pool key."""
+    value: Any                  # device array, or (int8 array, scale)
+    chunks: int
+    key: tuple
+
+
 class _Loader(threading.Thread):
     """Dedicated load queue: walks the plan's chunk tasks in op order,
     emulating the storage stage at `disk_bw` (0 = RAM speed), device_puts
-    each chunk (JAX async dispatch = the independent DMA queue) and flags
+    the chunks (JAX async dispatch = the independent DMA queue) and flags
     weights whose chunks have all arrived. With `quantized` host chunks
     ((int8, scale) tuples) the wire/storage bytes are the int8 payload.
 
+    The transfer unit is the slab: each maximal run of a task's missing
+    chunks that are plain consecutive row views of one host array moves
+    in one device_put of their rows, since the per-put cost, not the link,
+    sets the pace of 1 MiB puts. Quantised chunks, and chunks that are not
+    such views, move one put each. ``arrived`` holds each weight's
+    ``_Piece``s (chunks and slabs) in chunk order.
+
     When bound to a WeightCache, every chunk is probed in the pool first —
     prefetched or previously-streamed chunks skip the storage stage and the
-    device_put entirely — and freshly-loaded chunks are checked in pinned
-    so LRU pressure cannot drop bytes that are about to be consumed.
+    device_put entirely — and freshly-loaded chunks and slabs are checked
+    in pinned so LRU pressure cannot drop bytes that are about to be
+    consumed. A slab's key, ``(cache_key, w, ("rows", lo, hi))``, is one no
+    chunk probe asks for.
 
     An exception on this thread (e.g. HBM exhausted in a device_put) is
     kept in ``error`` and re-raised by ``check`` on the compute thread.
@@ -308,7 +384,7 @@ class _Loader(threading.Thread):
                     max(0, min(t.chunk_hi, n) - t.chunk_lo)
                 if planned[t.weight] >= n:
                     self.due.setdefault(t.weight, l)
-        self.arrived: Dict[str, list] = {}
+        self.arrived: Dict[str, List[_Piece]] = {}
         self.assembled: Dict[str, jax.Array] = {}   # whole-weight pool hits
         self.uncached_bytes: Dict[str, int] = {}    # pool-rejected transients
         self.ready: Dict[str, threading.Event] = {
@@ -318,6 +394,7 @@ class _Loader(threading.Thread):
         self.misses = 0
         self.streamed_chunks = 0
         self.streamed_bytes = 0
+        self.h2d_puts = 0
         self.put_s = 0.0
         self.lock = threading.Lock()
 
@@ -326,32 +403,62 @@ class _Loader(threading.Thread):
         if ev is not None:
             ev.set()
 
-    def _load_chunk(self, w: str, ci: int, chunk):
-        """Pool probe -> storage sleep -> device_put -> pinned check-in."""
-        if isinstance(chunk, tuple):                   # (int8, scale) host
-            nbytes = chunk[0].nbytes
+    def _arrive(self, w: str, piece: _Piece):
+        with self.lock:
+            self.arrived.setdefault(w, []).append(piece)
+
+    def _put(self, w: str, lo: int, hi: int):
+        """Storage sleep -> one device_put -> pinned check-in of chunks
+        ``lo..hi-1`` of ``w``, one chunk or one slab of them."""
+        hcs = self.host_chunks[w]
+        if hi - lo == 1:
+            host, key = hcs[lo], (self.cache_key, w, lo)
         else:
-            nbytes = chunk.nbytes
-        if self.cache is not None:
-            cached = self.cache.acquire((self.cache_key, w, ci))
-            if cached is not None:
-                self.hits += 1
-                return cached
-            self.misses += 1
+            host = _slab(hcs[lo:hi])
+            key = (self.cache_key, w, ("rows", lo, hi))
+        nbytes = _wire_bytes(host)
         if self.disk_bw > 0:
             time.sleep(nbytes / self.disk_bw)
         t = time.perf_counter()
-        arr = put_chunk(chunk, self.device)
-        self.streamed_chunks += 1
-        self.streamed_bytes += int(nbytes)
+        arr = put_chunk(host, self.device)
+        self.h2d_puts += 1
+        self.streamed_chunks += hi - lo
+        self.streamed_bytes += nbytes
         if self.cache is not None:
-            if not self.cache.put((self.cache_key, w, ci), arr, nbytes,
-                                  pin=True):
+            if not self.cache.put(key, arr, nbytes, pin=True):
                 with self.lock:
                     self.uncached_bytes[w] = \
-                        self.uncached_bytes.get(w, 0) + int(nbytes)
+                        self.uncached_bytes.get(w, 0) + nbytes
         self.put_s += time.perf_counter() - t
-        return arr
+        self._arrive(w, _Piece(arr, hi - lo, key))
+
+    def _put_run(self, w: str, lo: int, hi: int):
+        """Put the missing chunks ``lo..hi-1`` of ``w``, one slab per
+        stretch of them, or equal slabs of at most ``SLAB_BYTES``."""
+        hcs = self.host_chunks[w]
+        for a, b in _stretches(hcs, lo, hi):
+            n = -(-sum(_wire_bytes(hcs[i]) for i in range(a, b))
+                  // SLAB_BYTES)
+            step = -(-(b - a) // max(n, 1))
+            for i in range(a, b, step):
+                self._put(w, i, min(i + step, b))
+
+    def _load_task(self, w: str, lo: int, hi: int):
+        """Probe each chunk ``lo..hi-1`` of ``w`` in the pool, in order;
+        put each run of misses between the hits."""
+        run = lo
+        if self.cache is not None:
+            for ci in range(lo, hi):
+                key = (self.cache_key, w, ci)
+                cached = self.cache.acquire(key)
+                if cached is None:
+                    self.misses += 1
+                    continue
+                self.hits += 1
+                self._put_run(w, run, ci)
+                self._arrive(w, _Piece(cached, 1, key))
+                run = ci + 1
+        self._put_run(w, run, hi)
 
     def run(self):
         try:
@@ -402,13 +509,11 @@ class _Loader(threading.Thread):
                             self.ready[w].set()
                             continue
                         self.misses += 1
-                    hcs = self.host_chunks[w]
-                    for ci in range(task.chunk_lo,
-                                    min(task.chunk_hi, len(hcs))):
-                        arr = self._load_chunk(w, ci, hcs[ci])
-                        with self.lock:
-                            self.arrived.setdefault(w, []).append(arr)
-                if len(self.arrived.get(w, ())) >= len(hcs):
+                    n = len(self.host_chunks[w])
+                    self._load_task(w, task.chunk_lo, min(task.chunk_hi, n))
+                with self.lock:
+                    covered = sum(p.chunks for p in self.arrived.get(w, ()))
+                if covered >= n:
                     self.ready[w].set()
 
 
@@ -466,9 +571,8 @@ class StreamingExecutor:
                 uncached = sum(loader.uncached_bytes.values())
             return self.cache.used_bytes() + sum(transient.values()) + uncached
         with loader.lock:
-            inflight = sum(
-                int(c[0].nbytes if isinstance(c, tuple) else c.nbytes)
-                for lst in loader.arrived.values() for c in lst)
+            inflight = sum(_wire_bytes(p.value)
+                           for lst in loader.arrived.values() for p in lst)
         return sum(int(v.nbytes) for v in dev.values()) + inflight
 
     def begin(self, tokens: np.ndarray, batch: int = -1) -> ExecState:
@@ -561,47 +665,77 @@ class StreamingExecutor:
                             full = loader.assembled.get(wname) \
                                 if cache is not None else None
                             if full is None:
-                                if loader.ready[wname].is_set():
-                                    loader.check()
-                                else:
-                                    stats.stall_events += 1
-                                    t = time.perf_counter()
-                                    with jax.profiler.TraceAnnotation(
-                                            "flashmem.exec.wait_weight",
-                                            **meta):
+                                stalled = not loader.ready[wname].is_set()
+                                t = time.perf_counter()
+                                # one wait_weight span per stall, held
+                                # across the loader wait and the landing
+                                wait = contextlib.ExitStack()
+                                with wait:
+                                    if stalled:
+                                        wait.enter_context(
+                                            jax.profiler.TraceAnnotation(
+                                                "flashmem.exec.wait_weight",
+                                                **meta))
                                         loader.wait_ready(wname, op.index)
+                                    else:
+                                        loader.check()
+                                    full = loader.assembled.get(wname) \
+                                        if cache is not None else None
+                                    if full is None:
+                                        # `got` stays referenced until the
+                                        # next assembly or the end of this
+                                        # call: freeing the chunks as soon
+                                        # as their concatenate was
+                                        # dispatched made it block, and
+                                        # cost a streamed 2.7B batch 6 to 7%
+                                        # of its rate on a TPU v5e
+                                        with loader.lock:
+                                            got = loader.arrived.pop(wname,
+                                                                     [])
+                                        # a put returns before its bytes
+                                        # land
+                                        landing = [p.value[0] if isinstance(
+                                            p.value, tuple) else p.value
+                                            for p in got]
+                                        if not all(a.is_ready()
+                                                   for a in landing):
+                                            if not stalled:
+                                                stalled = True
+                                                wait.enter_context(
+                                                    jax.profiler
+                                                    .TraceAnnotation(
+                                                        "flashmem.exec."
+                                                        "wait_weight",
+                                                        **meta))
+                                            jax.block_until_ready(landing)
+                                if stalled:
+                                    stats.stall_events += 1
                                     stats.stall_s += time.perf_counter() - t
-                                full = loader.assembled.get(wname) \
-                                    if cache is not None else None
                             if full is None:
                                 t = time.perf_counter()
                                 with jax.profiler.TraceAnnotation(
                                         "flashmem.exec.assemble", **meta):
-                                    # `got` stays referenced until the next
-                                    # assembly or the end of this call:
-                                    # freeing the chunks as soon as their
-                                    # concatenate was dispatched made it
-                                    # block, and cost a streamed 2.7B batch
-                                    # 6 to 7% of its rate on a TPU v5e
-                                    with loader.lock:
-                                        got = loader.arrived.pop(wname, [])
+                                    hcs = host_chunks[wname]
+                                    parts = [p.value for p in got]
                                     # plan miss
-                                    if len(got) < len(host_chunks[wname]):
-                                        for c in host_chunks[wname][len(got):]:
-                                            got.append(
-                                                put_chunk(c, self.device))
-                                    got = [g[0].astype(jnp.float32) * g[1]
-                                           if isinstance(g, tuple) else g
-                                           for g in got]
-                                    full = got[0] if len(got) == 1 else \
-                                        jnp.concatenate(got, axis=0)
+                                    for c in hcs[sum(p.chunks
+                                                     for p in got):]:
+                                        parts.append(put_chunk(c, self.device))
+                                    parts = [g[0].astype(jnp.float32) * g[1]
+                                             if isinstance(g, tuple) else g
+                                             for g in parts]
+                                    full = parts[0] if len(parts) == 1 else \
+                                        jnp.concatenate(parts, axis=0)
                                     if cache is not None:
-                                        # chunk entries are consumed into
-                                        # the assembled weight; re-key so
-                                        # future runs hit it whole
-                                        for ci in range(
-                                                len(host_chunks[wname])):
+                                        # chunk and slab entries are
+                                        # consumed into the assembled
+                                        # weight; re-key so future runs
+                                        # hit it whole
+                                        for ci in range(len(hcs)):
                                             cache.remove((key, wname, ci))
+                                        for p in got:
+                                            if p.chunks > 1:
+                                                cache.remove(p.key)
                                         with loader.lock:
                                             loader.uncached_bytes.pop(
                                                 wname, None)
@@ -611,6 +745,23 @@ class StreamingExecutor:
                                             transient[wname] = \
                                                 int(full.nbytes)
                                 stats.assemble_s += time.perf_counter() - t
+                                # a streamed weight's op waits for its bytes
+                                # (above) and for the device to run the ops
+                                # before it, as when each put returned on
+                                # landing: a loop that ran ahead would queue
+                                # ops whose activations, and the weights the
+                                # pool evicts under them, the device holds
+                                # past the pool's budget (+3 GiB for a
+                                # streamed 2.7B on a TPU v5e); no name
+                                # may hold the registers past this wait
+                                if not all(a.is_ready()
+                                           for a in st.regs.values()):
+                                    t = time.perf_counter()
+                                    with jax.profiler.TraceAnnotation(
+                                            "flashmem.exec.sync", **meta):
+                                        jax.block_until_ready(
+                                            list(st.regs.values()))
+                                    stats.sync_s += time.perf_counter() - t
                             dev[wname] = full
                         warr = dev[wname]
                     st.regs = m.programs[op_tag(op.name)](st.regs, warr)
@@ -647,6 +798,7 @@ class StreamingExecutor:
         stats.streamed_chunks += loader.streamed_chunks
         stats.streamed_bytes += loader.streamed_bytes
         stats.put_s += loader.put_s
+        stats.h2d_puts += loader.h2d_puts
         stats.peak_bytes = max(stats.residency, default=0)
         stats.avg_bytes = float(np.mean(stats.residency)) \
             if stats.residency else 0
