@@ -7,13 +7,14 @@ the causal half of the score and value products (query ``i`` meets keys
 ``0..i``) and moves q, k, v and the output once. A kernel that computes
 masked blocks or materialises the scores does more work than is counted
 here, so its roofline share shows that waste and can never exceed 100%.
-The served program keeps activations and weights in float32.
+What one model's batch calls is its family's to say
+(``families/<family>.py``: ``batch_calls``, ``model_flops``).
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import Dict, Tuple
+from typing import Tuple
 
 F32 = 4
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
@@ -48,23 +49,3 @@ def least_time(flops: float, nbytes: float, pk: dict) -> float:
     """The roofline's least time: compute or memory, whichever binds."""
     return max(flops / pk["flops"], nbytes / pk["hbm_bytes_per_s"])
 
-
-def batch_calls(dm: dict, batch: int, seq: int) -> Dict[str, list]:
-    """Every call of the served op programs one (batch, seq) execution of
-    a GPT-Neo stack makes, as (flops, bytes) per call, keyed by program."""
-    d, dff, L = dm["d"], dm["dff"], dm["layers"]
-    rows = batch * seq
-    per_layer = [matmul(rows, d, d)] * 4 + [matmul(rows, d, dff),
-                                           matmul(rows, dff, d)]
-    return {"f_matmul": per_layer * L,
-            "f_attn": [attention(batch, seq, d)] * L}
-
-
-def model_flops(dm: dict, tokens: int) -> float:
-    """Model operations of one request of ``tokens`` real tokens: the
-    layers' matmuls and causal attention (embedding lookup, norms and
-    elementwise work not counted)."""
-    d, dff, L = dm["d"], dm["dff"], dm["layers"]
-    mm = 2.0 * tokens * (4 * d * d + 2 * d * dff)
-    att = 2.0 * d * tokens * (tokens + 1)
-    return L * (mm + att)

@@ -3,10 +3,10 @@ and ``--seed``, warm it up, drive its traffic mix through the serving
 engine's online loop for the window, drain, check what was served against
 the plain reference, and reduce the run to the cell's metrics.
 
-Everything that belongs to one configuration, traffic mix or per-layer
-metric is a file found by name (``configs/``, ``traffic/``,
-``metrics/``); this module reads them and holds nothing of its own about
-any of them.
+Everything that belongs to one configuration, model family, traffic mix
+or per-layer metric is a file found by name (``configs/``, ``families/``,
+``traffic/``, ``metrics/``); this module reads them and holds nothing of
+its own about any of them.
 """
 from __future__ import annotations
 
@@ -19,6 +19,7 @@ import tempfile
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -27,9 +28,7 @@ import numpy as np
 
 import arrivals
 import counts
-import system
 import trace_reduce
-from references import gptneo
 
 from repro.core.streaming import op_tag
 from repro.serving.batcher import BatcherConfig
@@ -62,6 +61,7 @@ class Cell:
     traffic: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    family: ModuleType          # families/<config's family>.py
 
 
 def bench_file(root: Path) -> Path:
@@ -85,22 +85,40 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     configs = {c["name"]: c for c in bench["configs"]}
     cfg_path = root.parents[1] / configs[w["config"]]["file"]
     config = json.loads(cfg_path.read_text())
+    if "family" not in config:
+        raise CellError(f"{cfg_path.name} names no model family")
+    fam = family(config["family"], root)
     traffic = arrivals.load(root / "traffic" / f"{w['traffic']}.json")
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or workload in m["workloads"]]
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"]
                  if _reports(m, workload, names)]
-    return Cell(workload, int(w["chips"]), config, traffic, e2e, per_layer)
+    return Cell(workload, int(w["chips"]), config, traffic, e2e, per_layer,
+                fam)
+
+
+def _load(path: Path, module: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(module, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(name: str, root: Path = ROOT):
     """The ``read(run)`` function of per-layer metric ``name``."""
-    path = root / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(root / "metrics" / f"{name}.py", f"metric_{name}").read
+
+
+def family(name: str, root: Path = ROOT) -> ModuleType:
+    """Model family ``name``: the module ``families/<name>.py``, which
+    holds everything the harness needs of one architecture (``dims``,
+    ``instance_key``, ``host_model``, ``forward``, ``batch_calls``,
+    ``model_flops``, ``cpu_arch``, ``PUBLISHED``)."""
+    path = root / "families" / f"{name}.py"
+    if not path.is_file():
+        raise CellError(f"no model family {name!r}: {path} is missing")
+    return _load(path, f"family_{name}")
 
 
 # ---------------------------------------------------------------------------
@@ -112,11 +130,7 @@ class Model:
     name: str
     index: int
     dims: dict
-    seed: int
-
-    @property
-    def key(self):
-        return gptneo.instance_key(self.seed, self.index)
+    key: object                 # the family's instance key of the seed
 
 
 @dataclass
@@ -125,12 +139,13 @@ class Deployment:
     models: List[Model]
     serve: ServeConfig
     max_batch: int
+    family: ModuleType
 
 
 def build(cell: Cell, seed: int) -> Deployment:
     """The cell's models, made from the seed, registered in configuration
     order on one streaming engine with a shared pool."""
-    cfg = cell.config
+    cfg, fam = cell.config, cell.family
     eng = cfg["engine"]
     engine = ServingEngine(
         policy=eng["policy"], chunk_bytes=int(eng["chunk_bytes"]),
@@ -141,16 +156,15 @@ def build(cell: Cell, seed: int) -> Deployment:
     models = []
     for i, m in enumerate(cfg["models"]):
         arch = cfg["archs"][m["arch"]]
-        mcfg = system.model_config(m["arch"], arch)
-        model = Model(m["name"], i, gptneo.dims(arch), seed)
+        model = Model(m["name"], i, fam.dims(arch), fam.instance_key(seed, i))
         t0 = time.perf_counter()
-        weights = system.host_weights(model.key, model.dims)
-        hm = system.host_model(mcfg, weights, seq=int(cfg["built_seq"]),
-                               batch=int(cfg["built_batch"]),
-                               programs=programs.get(m["arch"]))
+        hm = fam.host_model(m["arch"], arch, model.dims, model.key,
+                            seq=int(cfg["built_seq"]),
+                            batch=int(cfg["built_batch"]),
+                            programs=programs.get(m["arch"]))
         programs[m["arch"]] = hm.programs
         engine.register(m["name"], hm)
-        mib = sum(a.nbytes for a in weights.values()) / MiB
+        mib = sum(a.nbytes for a in hm.host_weights.values()) / MiB
         log(f"model {m['name']}: {m['arch']} layers {model.dims['layers']} "
             f"d {model.dims['d']} {mib:.1f} MiB made in "
             f"{time.perf_counter() - t0:.2f}s")
@@ -158,7 +172,7 @@ def build(cell: Cell, seed: int) -> Deployment:
     sv = cfg["serve"]
     serve = ServeConfig(scheduler=sv["scheduler"], batcher=BatcherConfig(
         max_batch=int(sv["max_batch"]), max_wait_s=float(sv["max_wait_s"])))
-    return Deployment(engine, models, serve, int(sv["max_batch"]))
+    return Deployment(engine, models, serve, int(sv["max_batch"]), fam)
 
 
 def shapes(cell: Cell, max_batch: int) -> List[tuple]:
@@ -431,9 +445,9 @@ def reference_outputs(dep: Deployment, win: Window, ids: List[int],
         mine = [i for i in ids if win.sent[i].model == m.name]
         if not mine:
             continue
-        refs = gptneo.forward(m.key, m.dims,
-                              [win.sent[i].tokens[0] for i in mine],
-                              precision, mode)
+        refs = dep.family.forward(m.key, m.dims,
+                                  [win.sent[i].tokens[0] for i in mine],
+                                  precision, mode)
         out.update(zip(mine, refs))
     return out
 

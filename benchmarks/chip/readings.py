@@ -54,7 +54,8 @@ def step_mfu(run) -> Optional[float]:
         return None
     dims = {m.name: m.dims for m in run.dep.models}
     sent = run.win.sent
-    flops = sum(counts.model_flops(dims[r.model], sent[r.req_id].length)
+    flops = sum(run.dep.family.model_flops(dims[r.model],
+                                           sent[r.req_id].length)
                 for r in _ok(run))
     t = sum(s.init_s + s.exec_s for s in run.win.stats)
     return 100.0 * flops / (t * run.peaks["flops"]) if t > 0 else None
@@ -69,10 +70,11 @@ def roofline(run, program: str) -> Optional[float]:
     if n == 0 or t_dev <= 0:
         return None
     dims = {m.name: m.dims for m in run.dep.models}
+    calls = run.dep.family.batch_calls
     least = sum(counts.least_time(f, b, run.peaks)
                 for bt in run.batches
-                for f, b in counts.batch_calls(dims[bt.model], bt.size,
-                                               bt.padded)[program])
+                for f, b in calls(dims[bt.model], bt.size,
+                                  bt.padded)[program])
     return 100.0 * least / t_dev
 
 

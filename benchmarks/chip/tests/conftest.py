@@ -13,13 +13,16 @@ for p in (HERE.parents[1] / "src", HERE):
 
 
 def shrink(cell, *, d=128, layers=2, heads=4, vocab=1024):
-    """Cut a cell's models to a CPU size: width, depth, heads and
-    vocabulary; prompts of 16/32/64 tokens, a 1 MiB pool that holds about
-    half of each model, and small chunks, so the streaming path runs."""
-    for a in cell.config["archs"].values():
-        a.update(hidden_size=d, num_layers=layers, num_heads=heads,
-                 vocab_size=vocab)
-    per_model = 4 * (vocab * d + layers * (12 * d * d + 4 * d) + 2 * d)
+    """Cut a cell's models to a CPU size by their family's ``cpu_arch``:
+    width, depth, heads and vocabulary; prompts of 16/32/64 tokens, a 1
+    MiB pool that holds about half of each model, and small chunks, so
+    the streaming path runs."""
+    archs = cell.config["archs"]
+    per_model = 0
+    for name, a in archs.items():
+        archs[name], nbytes = cell.family.cpu_arch(
+            a, d=d, layers=layers, heads=heads, vocab=vocab)
+        per_model = max(per_model, nbytes)
     cell.config["engine"]["budget_mib"] = 1
     cell.config["engine"]["chunk_bytes"] = max(4096, per_model // 64)
     pl = cell.traffic["prompt_len"]
@@ -42,6 +45,7 @@ def trio_cell():
     cell = harness.load_cell("neo27-offload-batch")
     cell.name = "neo-trio"
     cell.config = json.loads((HERE / "configs" / "neo-trio.json").read_text())
+    cell.family = harness.family(cell.config["family"])
     cell.traffic = {"loop": "closed", "clients": 8,
                     "popularity": [6, 3, 2],
                     "prompt_len": {"values": [256, 512, 1024],
@@ -54,8 +58,8 @@ def trio_cell():
 def tiny():
     import harness
 
-    def make(workload, **kw):
+    def make(workload, root=harness.ROOT, **kw):
         cell = trio_cell() if workload == "neo-trio" \
-            else harness.load_cell(workload)
+            else harness.load_cell(workload, root)
         return shrink(cell, **kw)
     return make

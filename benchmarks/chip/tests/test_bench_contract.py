@@ -100,11 +100,17 @@ def test_one_layer_name_per_layer():
 
 
 def test_configs_are_used_and_keep_published_widths():
+    """Each arch is its family's ``PUBLISHED`` entry but for the keys the
+    configuration lists in ``reduced``."""
+    import harness
     used = {w["config"] for w in BENCH["workloads"]}
     for c in BENCH["configs"]:
         assert c["name"] in used
         cfg = json.loads((REPO / c["file"]).read_text())
         assert cfg["reduced"] == c["reduced"]
-        for arch in cfg["archs"].values():
-            assert arch["hidden_size"] in (2048, 2560)
-            assert arch["num_layers"] in (24, 32)
+        published = harness.family(cfg["family"]).PUBLISHED
+        for name, arch in cfg["archs"].items():
+            pub = published[name]
+            changed = {k for k in set(arch) | set(pub)
+                       if arch.get(k, KeyError) != pub.get(k, KeyError)}
+            assert changed <= set(c["reduced"]), (c["name"], name, changed)

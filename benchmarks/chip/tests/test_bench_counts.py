@@ -3,6 +3,9 @@ served program, and the peaks table."""
 import pytest
 
 import counts
+import harness
+
+gptneo = harness.family("gptneo")
 
 
 def test_matmul_hand_count():
@@ -19,7 +22,7 @@ def test_attention_hand_count():
 
 def test_batch_calls_cover_every_served_matmul_and_attention():
     dm = {"d": 8, "dff": 32, "layers": 3}
-    calls = counts.batch_calls(dm, 2, 5)
+    calls = gptneo.batch_calls(dm, 2, 5)
     assert len(calls["f_matmul"]) == 6 * 3
     assert len(calls["f_attn"]) == 3
     rows = 2 * 5
@@ -33,7 +36,7 @@ def test_model_flops_is_matmuls_plus_causal_attention():
     tokens = 5
     mm = 2 * tokens * (4 * 64 + 2 * 8 * 32)
     att = counts.attention(1, tokens, 8)[0]
-    assert counts.model_flops(dm, tokens) == 2 * (mm + att)
+    assert gptneo.model_flops(dm, tokens) == 2 * (mm + att)
 
 
 def test_least_time_takes_the_binding_roof():
